@@ -300,6 +300,8 @@ def main() -> None:
                     help="allowed per-stage wall-time growth fraction for "
                          "the synchronous engines (default .25)")
     args = ap.parse_args()
+    from repro.launch.runtime import enable_compile_cache
+    enable_compile_cache()
     if args.check_against and not args.pipeline_json:
         ap.error("--check-against requires --pipeline-json (the gate "
                  "compares a fresh pipeline run)")

@@ -48,19 +48,6 @@ from .pipeline import MapperConfig
 AXIS = "shards"
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: the top-level API landed after
-    0.4.x; older releases carry it in jax.experimental with ``check_rep``
-    instead of ``check_vma`` (both disabled — scan carries are created
-    fresh inside the body)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 def stage_b_affine_capacity(n_entries: int, cfg: MapperConfig,
                             frac: float | None = None) -> int:
     """Static survivor capacity for stage B's affine pass.
@@ -106,9 +93,14 @@ class ShardedIndex:
     w: int
     eth: int
 
-    def device_arrays(self):
-        return (jnp.asarray(self.uniq_kmers), jnp.asarray(self.offsets),
-                jnp.asarray(self.positions), jnp.asarray(self.segments))
+    def device_arrays(self, mesh):
+        """Place the index on ``mesh`` once, shard ``s`` on device ``s``
+        (leading axis split over ``AXIS``), so the shard_map program
+        reads it where it lies instead of resharding it every call."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        shard = NamedSharding(mesh, P(AXIS))
+        return tuple(jax.device_put(x, shard) for x in (
+            self.uniq_kmers, self.offsets, self.positions, self.segments))
 
     @classmethod
     def from_partitions(cls, parts, *, read_len: int, k: int, w: int,
@@ -374,9 +366,10 @@ def make_distributed_mapper(mesh, cfg: MapperConfig, n_shards: int,
                 aff_drop[None])
 
     pspec = P(AXIS)
-    fn = _shard_map(step, mesh,
-                    in_specs=(pspec, pspec, pspec, pspec, pspec),
-                    out_specs=(pspec,) * 6)
+    # check_vma off: the scan carries are created fresh inside the body
+    fn = jax.shard_map(step, mesh=mesh,
+                       in_specs=(pspec, pspec, pspec, pspec, pspec),
+                       out_specs=(pspec,) * 6, check_vma=False)
     return jax.jit(fn), aff_cap
 
 

@@ -287,14 +287,11 @@ class MappingPlan:
         return ("single", self.engine, self.chunk)
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions: >= 0.5 takes explicit Auto
-    axis types; older releases have implicitly-auto axes only.  The single
-    home of this shim — ``launch.mesh`` builds its meshes through it."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:  # jax < 0.5
-        return jax.make_mesh(shape, axes)
+def make_auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by
+    the compiler) — the one place meshes are built; ``launch.mesh`` uses
+    it too."""
+    from jax.sharding import AxisType
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
@@ -303,7 +300,7 @@ def _flat_mesh(n_shards: int | None):
     """Default flat shard mesh (``launch.mesh.make_genomics_mesh`` without
     the core->launch dependency)."""
     n = n_shards or len(jax.devices())
-    return make_mesh_compat((n,), (AXIS,))
+    return make_auto_mesh((n,), (AXIS,))
 
 
 def _host_positions(pos):
@@ -522,7 +519,7 @@ class Mapper:
                 sidx = shard_index(index, S)
                 self.index = index
             self.sharded_index = sidx
-            self._dev = sidx.device_arrays()
+            self._dev = sidx.device_arrays(self.mesh)
 
     # ------------------------------------------------------------- planning
 

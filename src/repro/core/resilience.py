@@ -73,6 +73,26 @@ class InjectedFault(RuntimeError):
     """A deterministic fault raised by ``FaultInjector`` (chaos tests)."""
 
 
+def is_compile_error(exc: BaseException) -> bool:
+    """True for an error raised while lowering or compiling a program: a
+    ``NotImplementedError`` from the Pallas lowering, or a Mosaic compile
+    error.  It is a fault of the code on this platform, not of the data
+    or the device — a retry cannot clear it and a lower ladder rung would
+    hide it behind the reference backend — so ``ResilientMapper`` lets it
+    propagate.  The exception's cause/context chain is searched too."""
+    seen = set()
+    e = exc
+    while e is not None and id(e) not in seen:
+        seen.add(id(e))
+        names = {c.__name__ for c in type(e).__mro__}
+        if (isinstance(e, NotImplementedError)
+                or names & {"LoweringException", "MosaicError"}
+                or "Mosaic failed to compile" in str(e)):
+            return True
+        e = e.__cause__ or e.__context__
+    return False
+
+
 class ShedError(RuntimeError):
     """``MappingService.submit`` rejected a request: the pending queue is
     full and the admission policy is ``shed``.  Recoverable — resubmit
@@ -486,7 +506,9 @@ class ResilientMapper:
     ``counters`` (retries, failed reads/blocks, degrade steps) ride in
     ``stats.extra["resilience"]``.  ``map_segments`` is the serving
     layer's lower-level entry — it returns the ordered block cover so
-    ``MappingService.flush`` can resolve per-request spans.
+    ``MappingService.flush`` can resolve per-request spans.  An error
+    from lowering or compiling a kernel (``is_compile_error``) is not
+    contained: it propagates on the first attempt.
 
     With no injector and no faults the wrapper is one ``try`` per block
     — the armed-but-idle overhead the ``resilience_overhead`` benchmark
@@ -564,6 +586,8 @@ class ResilientMapper:
                 self.ladder.ok()
                 return [(n, res)], counters
             except Exception as e:  # noqa: BLE001 — containment boundary
+                if is_compile_error(e):
+                    raise
                 last_exc = e
                 attempts += 1
                 if attempts < pol.max_attempts:

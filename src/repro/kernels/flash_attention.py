@@ -68,7 +68,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, kv_chunk: int, causal: bool,
                                              "interpret"))
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            q_chunk: int = 512, kv_chunk: int = 512,
-                           interpret: bool = True):
+                           interpret: bool):
     """q (B, H, S, hd); k/v (B, KV, S, hd) -> (B, H, S, hd).
 
     H must be a multiple of KV (GQA); S divisible by the chunk sizes.

@@ -49,7 +49,7 @@ def _kernel(seq_ref, hash_ref, pos_ref, *, k: int, w: int, n_win: int):
 
 @functools.partial(jax.jit, static_argnames=("k", "w", "block_r", "interpret"))
 def minimizer_pallas(seqT: jnp.ndarray, *, k: int = 12, w: int = 30,
-                     block_r: int = 512, interpret: bool = True):
+                     block_r: int = 512, interpret: bool):
     """seqT (L, R) uint8 base codes -> (hashes (n_win, R) uint32,
     positions (n_win, R) int32), n_win = L - (w + k - 1) + 1."""
     L, R = seqT.shape

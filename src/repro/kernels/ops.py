@@ -1,8 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
-the kernel body runs as traced JAX ops, proving correctness of the exact
-code that compiles for TPU.  ``on_tpu()`` flips to compiled mode.
+``on_tpu()`` is the one place that decides how a kernel runs: compiled
+on a TPU; elsewhere in ``interpret=True`` mode, where the kernel body
+runs as traced JAX ops (correctness of the exact code that compiles for
+the TPU, not its speed).  The ``*_pallas`` kernels take ``interpret``
+with no default, so a direct caller must choose.
 
 Wrappers handle the (instances-last) transposes and padding to the block
 size so callers keep the natural (R, n) layout.

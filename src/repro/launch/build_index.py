@@ -79,7 +79,7 @@ def run(args) -> int:
             logjson.disable()
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser(
         prog="repro.launch.build_index",
         description="Build a sharded on-disk genome index from a FASTA "
@@ -119,7 +119,10 @@ def main():
     ap.add_argument("--log-json", action="store_true",
                     help="structured one-object-per-line JSON progress "
                          "on stderr")
-    return run(ap.parse_args())
+    args = ap.parse_args(argv)
+    from repro.launch.runtime import enable_compile_cache
+    enable_compile_cache()
+    return run(args)
 
 
 if __name__ == "__main__":

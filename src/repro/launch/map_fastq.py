@@ -157,6 +157,7 @@ def _run(args) -> int:
 
     from repro.io.sam import (emit_alignments, emit_paired_alignments,
                               sam_header)
+    from repro.launch.runtime import device_line
 
     t0 = time.perf_counter()
     injector = (FaultInjector.from_spec(args.inject)
@@ -232,7 +233,8 @@ def _run(args) -> int:
         f"map_fastq: {len(contigs)} contig(s), {n_indexed} indexed bases "
         f"({src}), read_len={rl}, topology={mapper.topology}, "
         f"paired={paired}, both_strands={cfg.both_strands}, "
-        f"engine={cfg.engine}, wf_backend={cfg.wf_backend}",
+        f"engine={cfg.engine}, wf_backend={cfg.wf_backend}, "
+        f"{device_line()}",
         event="start", contigs=len(contigs), indexed_bases=n_indexed,
         read_len=rl, topology=mapper.topology, paired=paired,
         engine=cfg.engine, wf_backend=cfg.wf_backend)
@@ -403,7 +405,7 @@ def _run(args) -> int:
     return 0
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser(
         prog="repro.launch.map_fastq",
         description="Map a FASTQ read set against a FASTA reference; "
@@ -491,7 +493,7 @@ def main():
     ap.add_argument("--k", type=int, default=12)
     ap.add_argument("--w", type=int, default=30)
     ap.add_argument("--eth", type=int, default=6)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.index_dir is not None:
         if args.reference is not None and args.reads is None:
             # `map_fastq --index-dir DIR reads.fq`: the sole positional
@@ -503,10 +505,11 @@ def main():
     elif args.reference is None:
         raise SystemExit("map_fastq: a FASTA reference (positional) or "
                          "--index-dir is required")
-    if args.topology == "mesh" and args.shards and \
-            "XLA_FLAGS" not in os.environ:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.shards}")
+    from repro.launch.runtime import emulate_host_devices, \
+        enable_compile_cache
+    if args.topology == "mesh":
+        emulate_host_devices(args.shards)
+    enable_compile_cache()
     try:
         return run(args)
     except BrokenPipeError:

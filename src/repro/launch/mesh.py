@@ -8,8 +8,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# single home of the jax-version mesh-construction shim
-from repro.core.mapper import make_mesh_compat as _make_mesh
+from repro.core.mapper import make_auto_mesh as _make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

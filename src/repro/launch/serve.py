@@ -8,10 +8,10 @@
 Both modes drive the unified ``repro.core.mapper.Mapper`` session API:
 
   * distributed (default) — ``Mapper(topology="mesh")`` batch loop: one
-    process per host on a real pod (mesh from the TPU environment); on
-    CPU it runs over virtual devices.  Stage B runs affine WF only on
-    compacted filter survivors; the unified ``MapperStats`` reports the
-    instance accounting.
+    process per host on a real pod (mesh from the TPU environment);
+    under ``JAX_PLATFORMS=cpu``, ``--shards N`` runs over N virtual
+    devices.  Stage B runs affine WF only on compacted filter survivors;
+    the unified ``MapperStats`` reports the instance accounting.
   * ``--service`` — the request-batching path: variable-sized request
     batches are coalesced by the pow-2 ``ReadBatcher`` into static bucket
     shapes (``repro.core.serving``).  ``--topology single`` (default)
@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import os
 import sys
 import time
 
@@ -146,8 +145,10 @@ def run_service(args) -> int:
     from repro.core.index import build_index
     from repro.core.mapper import Mapper
     from repro.core.pipeline import MapperConfig
+    from repro.core.resilience import MappingError
     from repro.core.serving import BatcherConfig
     from repro.data.genome import make_reference, sample_reads
+    from repro.launch.runtime import device_line
     from repro.obs import logjson
 
     ref = make_reference(args.genome, seed=0, repeat_frac=0.02)
@@ -161,10 +162,10 @@ def run_service(args) -> int:
     logjson.say(f"service: genome {len(ref)} bases, buckets "
                 f"[{args.bucket_min}..{args.bucket_max}], "
                 f"topology={mapper.topology}, stream={cfg.stream}, "
-                f"wf_backend={cfg.wf_backend}",
+                f"wf_backend={cfg.wf_backend}, {device_line()}",
                 event="start", file=sys.stdout,
                 genome=len(ref), topology=mapper.topology)
-    total = correct = 0
+    total = correct = failed = 0
     t0 = time.perf_counter()
     truth = {}
     for b in range(args.batches):
@@ -174,8 +175,14 @@ def run_service(args) -> int:
             rs = sample_reads(ref, n, seed=int(rng.integers(1 << 30)))
             truth[svc.submit(rs.reads)] = rs.true_pos
         for rid, res in svc.flush().items():
+            true_pos = truth.pop(rid)
+            if isinstance(res, MappingError):
+                failed += 1
+                print(f"request {rid}: {res.error_type}: {res.message}",
+                      file=sys.stderr)
+                continue
             total += len(res.position)
-            correct += int((np.abs(res.position - truth.pop(rid)) <= 6).sum())
+            correct += int((np.abs(res.position - true_pos) <= 6).sum())
     dt = time.perf_counter() - t0
     st = svc.batcher.stats
     waste = st["padded_reads"] / max(st["padded_reads"] + st["reads"], 1)
@@ -187,7 +194,11 @@ def run_service(args) -> int:
                 accuracy=round(correct / max(total, 1), 4))
     print(f"bucket hist {st['bucket_hist']}, lane padding waste {waste:.3f}")
     _print_mapper_stats(mapper, svc.totals)
-    return 0
+    rc = svc.resilient.counters
+    print(f"resilience: {rc['retries']} retries, {rc['failed_reads']} "
+          f"quarantined reads, {failed} failed request(s), engine ladder "
+          f"{svc.resilient.ladder.describe()}")
+    return 1 if failed else 0
 
 
 def run_distributed(args) -> int:
@@ -198,6 +209,7 @@ def run_distributed(args) -> int:
     from repro.core.pipeline import MapperConfig
     from repro.data.genome import make_reference, sample_reads
     from repro.launch.mesh import make_genomics_mesh
+    from repro.launch.runtime import device_line
 
     mesh = make_genomics_mesh(args.shards)
     n_shards = mesh.devices.size
@@ -207,7 +219,7 @@ def run_distributed(args) -> int:
     mapper = Mapper(idx, cfg, topology="mesh", mesh=mesh,
                     send_cap=args.send_cap)
     print(f"serving: {n_shards} shards, {len(idx.uniq_kmers)} minimizers, "
-          f"{len(ref)} bases")
+          f"{len(ref)} bases, {device_line()}")
     totals = dict(survivors=0, affine_instances=0,
                   padded_affine_instances=0, dropped_send=0,
                   dropped_affine=0, reverse_best=0)
@@ -226,7 +238,7 @@ def run_distributed(args) -> int:
     return 0
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--service", action="store_true",
                     help="request batcher + Mapper session service mode")
@@ -268,10 +280,11 @@ def main():
     ap.add_argument("--log-json", action="store_true",
                     help="structured one-object-per-line JSON progress "
                          "on stderr")
-    args, _ = ap.parse_known_args()
-    if args.shards and "XLA_FLAGS" not in os.environ:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.shards}")
+    args, _ = ap.parse_known_args(argv)
+    from repro.launch.runtime import emulate_host_devices, \
+        enable_compile_cache
+    emulate_host_devices(args.shards)
+    enable_compile_cache()
     fn = run_service if args.service else run_distributed
     with _obs(args, "serve"):
         return fn(args)

@@ -1,17 +1,8 @@
 import os
 import sys
 
-import jax
-
 # src/ layout without installation
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-# Shared version gate for the pre-existing seed failures on this
-# container's jax 0.4.37 (jax.sharding.AxisType, the remat
-# optimization_barrier differentiation rule, dict-valued cost_analysis —
-# all jax >= 0.5 features).  Test files import this and attach their own
-# per-failure skipif reasons.
-JAX_PRE_05 = jax.__version_info__ < (0, 5, 0)
 
 # Fall back to the vendored deterministic hypothesis stub when the real
 # package is unavailable (see tests/_stubs/hypothesis/__init__.py).

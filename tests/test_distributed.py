@@ -10,8 +10,6 @@ import sys
 
 import pytest
 
-from conftest import JAX_PRE_05
-
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 _MAPPER_SCRIPT = r"""
@@ -140,9 +138,6 @@ def test_distributed_mapper_8dev():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(JAX_PRE_05, reason="jax<0.5: jax.sharding.AxisType and "
-                    "the remat optimization_barrier differentiation rule "
-                    "are missing (pre-existing seed failure on jax 0.4.37)")
 def test_sharded_train_step_matches_unsharded():
     assert "DISTRIBUTED_LM_OK" in _run(_LM_SCRIPT)
 
@@ -207,8 +202,6 @@ print("ELASTIC_OK")
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(JAX_PRE_05, reason="jax<0.5: jax.sharding.AxisType is "
-                    "missing (pre-existing seed failure on jax 0.4.37)")
 def test_elastic_restore_across_mesh_shapes():
     """Checkpoint on a (2,4) mesh, restore + continue on (4,2): the step
     after restart produces the same loss as the uninterrupted run."""
@@ -256,8 +249,6 @@ print("LONGCTX_OK")
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(JAX_PRE_05, reason="jax<0.5: jax.sharding.AxisType is "
-                    "missing (pre-existing seed failure on jax 0.4.37)")
 def test_seq_sharded_decode_matches_unsharded():
     """batch=1 decode with the KV cache sequence sharded over the data axis
     (the long_500k configuration) matches unsharded decode."""

@@ -49,6 +49,9 @@ def _run_map_fastq(d, out_name, *argv, chunk_reads=16):
     env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..",
                                       "src") +
                          os.pathsep + env.get("PYTHONPATH", ""))
+    # a CPU run: the launcher gives `--shards N` mesh runs N virtual
+    # devices only when JAX is pinned to the CPU
+    env.setdefault("JAX_PLATFORMS", "cpu")
     cmd = [sys.executable, "-m", "repro.launch.map_fastq",
            str(d / "ref.fa"), *argv, "-o", str(d / out_name),
            "--chunk-reads", str(chunk_reads)]
@@ -249,6 +252,9 @@ def _run_index_cli(d, out_name, *argv, chunk_reads=16):
     env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..",
                                       "src") +
                          os.pathsep + env.get("PYTHONPATH", ""))
+    # a CPU run: the launcher gives `--shards N` mesh runs N virtual
+    # devices only when JAX is pinned to the CPU
+    env.setdefault("JAX_PLATFORMS", "cpu")
     cmd = [sys.executable, "-m", "repro.launch.map_fastq",
            "--index-dir", str(d / "idx"), str(d / "reads.fq"),
            *argv, "-o", str(d / out_name),

@@ -24,17 +24,6 @@ def _batch(cfg, B=2, S=32):
             "labels": jnp.ones((B, S), jnp.int32)}
 
 
-# The reduced configs train with remat, whose optimization_barrier has no
-# differentiation rule before jax 0.5 — a pre-existing seed failure on
-# this container's jax 0.4.37, gated as an explicit skip.  The forward
-# half stays live on old jax via test_forward_step_pre_jax05 below.
-from conftest import JAX_PRE_05  # noqa: E402
-
-
-@pytest.mark.skipif(JAX_PRE_05,
-                    reason="jax<0.5: no differentiation rule for "
-                           "optimization_barrier (remat train step; "
-                           "pre-existing seed failure on jax 0.4.37)")
 @pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_forward_and_train_step(arch):
     cfg = reduced(ARCHS[arch])
@@ -53,22 +42,6 @@ def test_forward_and_train_step(arch):
     delta = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()),
                          state[0], params)
     assert max(jax.tree.leaves(delta)) > 0
-
-
-@pytest.mark.skipif(not JAX_PRE_05,
-                    reason="forward covered by test_forward_and_train_step "
-                           "on jax>=0.5")
-@pytest.mark.parametrize("arch", sorted(ARCHS))
-def test_forward_step_pre_jax05(arch):
-    """Forward-pass half of the smoke test, kept live where the train
-    step is version-gated (train needs jax>=0.5, forward does not)."""
-    cfg = reduced(ARCHS[arch])
-    params = transformer.init_params(cfg, KEY)
-    B, S = 2, 32
-    batch = _batch(cfg, B, S)
-    logits, aux = transformer.forward(params, batch, cfg)
-    assert logits.shape == (B, S, cfg.vocab_size)
-    assert np.isfinite(np.asarray(logits, np.float32)).all()
 
 
 @pytest.mark.parametrize("arch", sorted(ARCHS))

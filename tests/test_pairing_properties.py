@@ -301,9 +301,9 @@ def test_exact_repeat_read_gets_zero_gap_distance2():
                          sat=cfg.sat_affine)
         assert q[0] == 0 and q[1] == MAPQ_MAX, engine
     # mesh path (1-shard in-process mesh): same calibration
-    from repro.core.mapper import make_mesh_compat
+    from repro.core.mapper import make_auto_mesh
     from repro.core.distributed import AXIS
-    mesh = make_mesh_compat((1,), (AXIS,))
+    mesh = make_auto_mesh((1,), (AXIS,))
     mres = Mapper(idx, MapperConfig.from_index(idx), topology="mesh",
                   mesh=mesh).map(np.concatenate([read, uniq]))
     assert mres.mapped.all()

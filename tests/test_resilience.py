@@ -291,3 +291,40 @@ def test_mapping_error_shape():
     e = MappingError("execution", "boom", n_reads=8, attempts=2)
     assert not e.ok and e.error_type == "execution"
     assert dataclasses.asdict(e)["n_reads"] == 8
+
+
+@pytest.mark.parametrize("fault", ["lowering", "injected"])
+def test_compile_errors_propagate_injected_faults_degrade(world,
+                                                          monkeypatch,
+                                                          fault):
+    """A kernel the compiler refuses must surface, not retry or step the
+    ladder down to the jnp rung (which would hand back reference results
+    and exit 0); an injected fault on the same rung still degrades."""
+    import jax
+
+    from repro.kernels import ops
+    idx, reads = world
+    cfg = MapperConfig(engine="compacted", wf_backend="pallas")
+    inj = None
+    if fault == "lowering":
+        def refuse(*a, **k):
+            raise NotImplementedError("Reductions over int8 not implemented")
+        monkeypatch.setattr(ops, "linear_wf", refuse)
+        jax.clear_caches()      # the stage jit must re-trace the kernel
+    else:
+        inj = FaultInjector(fail_engines=["pallas"])
+    rm = ResilientMapper(Mapper(idx, cfg, injector=inj), FAST, injector=inj)
+    try:
+        if fault == "lowering":
+            with pytest.raises(NotImplementedError, match="int8"):
+                rm.map(reads)
+            assert rm.counters["retries"] == 0
+            assert rm.counters["degraded_steps"] == 0
+            assert not rm.ladder.degraded
+        else:
+            res, mask, counters = rm.map(reads)
+            assert counters["degraded_steps"] == 1
+            assert rm.cfg.wf_backend == "jnp"
+            assert not mask.any() and res.mapped.all()
+    finally:
+        jax.clear_caches()

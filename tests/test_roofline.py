@@ -42,18 +42,6 @@ def _hlo_flops_unrolled(cfg, B, S):
     return c.cost_analysis()["flops"]
 
 
-# Compiled.cost_analysis() returns a per-computation *list* (not a dict)
-# before jax 0.5 — a pre-existing seed failure on this container's jax
-# 0.4.37, gated as an explicit skip.
-from conftest import JAX_PRE_05  # noqa: E402
-
-SKIP_PRE_05 = pytest.mark.skipif(
-    JAX_PRE_05,
-    reason="jax<0.5: Compiled.cost_analysis() returns a list, not a dict "
-           "(pre-existing seed failure on jax 0.4.37)")
-
-
-@SKIP_PRE_05
 @pytest.mark.parametrize("arch", ["smollm-135m", "olmo-1b"])
 def test_analytic_flops_vs_hlo_dense(arch):
     cfg = dataclasses.replace(reduced(ARCHS[arch]), remat=False, n_layers=3)
@@ -65,7 +53,6 @@ def test_analytic_flops_vs_hlo_dense(arch):
     assert model == pytest.approx(hlo, rel=0.15), (model, hlo)
 
 
-@SKIP_PRE_05
 def test_analytic_flops_vs_hlo_moe():
     cfg = dataclasses.replace(reduced(ARCHS["moonshot-v1-16b-a3b"]),
                               remat=False, n_layers=2)

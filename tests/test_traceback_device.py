@@ -161,3 +161,25 @@ def test_traceback_matches_banded_affine_plus_walk():
     np.testing.assert_array_equal(np.asarray(dm_s), np.asarray(dm_f))
     np.testing.assert_array_equal(np.asarray(ops_s), np.asarray(ops_f))
     np.testing.assert_array_equal(np.asarray(cnt_s), np.asarray(cnt_f))
+
+
+def test_saturated_walks_match_jnp_backend():
+    """Unrelated read/window pairs saturate, and their walks leave the
+    band: the kernel's row-by-row walk must still read the same cells
+    as the reference's flat ``take_along_axis`` (one wrap below 0, the
+    fill value past the end).  The pipeline masks these unmapped lanes,
+    but the kernel output is defined for them too."""
+    r = np.random.default_rng(23)
+    n, count = 48, 16
+    s1 = r.integers(0, 4, (count, n)).astype(np.uint8)
+    win = r.integers(0, 4, (count, n + 2 * ETH)).astype(np.uint8)
+    outs = {}
+    for backend in ("jnp", "pallas"):
+        outs[backend] = wfb.affine_traceback(
+            jnp.asarray(s1), jnp.asarray(win), eth=ETH, sat=SAT,
+            max_ops=2 * n + 2, backend=backend, block_r=16)
+    assert (np.asarray(outs["jnp"][0]) >= SAT).sum() >= count // 2
+    for a, b, name in zip(outs["jnp"], outs["pallas"],
+                          ("dist_end", "dist_min", "ops", "op_count")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)

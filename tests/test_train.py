@@ -16,16 +16,6 @@ from repro.train.trainer import FaultInjector, Trainer, TrainerConfig
 
 CFG = reduced(ARCHS["smollm-135m"])
 
-# The trainer's remat path emits optimization_barrier, whose
-# differentiation rule only exists in jax >= 0.5 — a pre-existing seed
-# failure on this container's jax 0.4.37, gated as an explicit skip.
-from conftest import JAX_PRE_05  # noqa: E402
-
-SKIP_PRE_05 = pytest.mark.skipif(
-    JAX_PRE_05,
-    reason="jax<0.5: no differentiation rule for optimization_barrier "
-           "(remat train step; pre-existing seed failure on jax 0.4.37)")
-
 
 def test_cosine_schedule_shape():
     lr = cosine_schedule(1e-3, warmup=10, total=100)
@@ -46,7 +36,6 @@ def test_adamw_converges_quadratic():
     np.testing.assert_allclose(np.asarray(params["w"]), [1.0, 1.0], atol=0.05)
 
 
-@SKIP_PRE_05
 def test_microbatch_equivalence():
     key = jax.random.key(0)
     params = transformer.init_params(CFG, key)
@@ -100,7 +89,6 @@ def test_checkpoint_shape_mismatch_rejected(tmp_path):
         ckpt.restore(str(tmp_path), 1, {"a": jnp.ones((3, 3))})
 
 
-@SKIP_PRE_05
 def test_trainer_fault_injection_and_resume(tmp_path):
     tc = TrainerConfig(total_steps=8, global_batch=4, seq_len=32,
                        ckpt_dir=str(tmp_path), ckpt_every=4, log_every=2,
@@ -162,7 +150,6 @@ def test_adafactor_converges_and_state_small():
     assert specs["w"]["vc"] == P("model")
 
 
-@SKIP_PRE_05
 def test_train_step_with_adafactor():
     from repro.train.optimizer import adafactor
     opt = adafactor(warmup=0, total_steps=4)
