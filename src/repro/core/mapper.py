@@ -116,7 +116,8 @@ class MapperStats:
     executed, what fixed-capacity buffers dropped) plus the session's
     cumulative plan-cache counters at the time of the run.  ``extra``
     carries the legacy per-path keys (``candidates_valid`` /
-    ``stage_times_s`` on the single-shard path, ``stage_b_*`` /
+    ``stage_times_s`` / ``d2h_syncs``, the blocking device-to-host reads,
+    on the single-shard path, ``stage_b_*`` /
     ``send_dropped`` on the mesh path) and backs the dict-style access
     (``stats["survivors"]``, ``dict(stats)``) the pre-``Mapper`` API
     exposed.
@@ -619,9 +620,15 @@ class Mapper:
     # ------------------------------------------------------------ execution
 
     def map(self, reads: np.ndarray) -> MappingResult:
-        """Plan + run one read batch; the single public mapping call."""
+        """Plan + run one read batch; the single public mapping call.
+        Traced as one ``repro/map`` span with the batch's ``reads`` and
+        ``syncs``, the blocking device-to-host reads the call made."""
         reads = np.asarray(reads)
-        return self.run(self.plan(len(reads)), reads)
+        with _tracing.span("repro/map", reads=len(reads)) as sp:
+            res = self.run(self.plan(len(reads)), reads)
+            if res.stats is not None and "d2h_syncs" in res.stats:
+                sp.set_metadata(syncs=res.stats["d2h_syncs"])
+        return res
 
     def map_pairs(self, reads1: np.ndarray, reads2: np.ndarray,
                   ) -> tuple[MappingResult, MappingResult]:
@@ -811,7 +818,7 @@ class Mapper:
         times = ({} if (self.cfg.profile or _tracing.ACTIVE is not None)
                  else None)
         t0 = time.perf_counter()
-        with _tracing.annotate("mesh_dispatch"):
+        with _tracing.span("repro/map/dispatch", stage="mesh"):
             pos, dist, dist2, dropped, n_surv, aff_drop = fn(
                 *self._dev, jnp.asarray(reads))
         t0 = streaming.timed(times, "dispatch", t0)

@@ -33,6 +33,7 @@ import dataclasses
 
 import numpy as np
 
+from ..obs import tracing as _tracing
 from .encoding import revcomp
 from .pipeline import MapperConfig, MappingResult
 
@@ -189,7 +190,20 @@ def _rescue(res_un, res_an, idx, reads_un, ref, cfg: MapperConfig,
             window, max_dist: int, max_windows: int, rescued) -> int:
     """Re-align the unmapped mates ``idx`` of ``res_un`` near their
     anchors in ``res_an``; fill accepted placements in-place (``res_un``
-    is already a private copy).  Returns the number rescued."""
+    is already a private copy).  Returns the number rescued.  Traced as a
+    ``repro/pairing/rescue`` span: the sweep's ``rows``, the ``bucket``
+    they were padded to, and the mates ``rescued``."""
+    with _tracing.span("repro/pairing/rescue") as sp:
+        rows, bucket, n_rescued = _rescue_sweep(
+            res_un, res_an, idx, reads_un, ref, cfg, window, max_dist,
+            max_windows, rescued)
+        sp.set_metadata(rows=rows, bucket=bucket, rescued=n_rescued)
+    return n_rescued
+
+
+def _rescue_sweep(res_un, res_an, idx, reads_un, ref, cfg: MapperConfig,
+                  window, max_dist: int, max_windows: int, rescued):
+    """``_rescue``'s work; returns (rows swept, bucket, mates rescued)."""
     import jax.numpy as jnp
 
     from . import wf_backend as wfb
@@ -225,7 +239,7 @@ def _rescue(res_un, res_an, idx, reads_un, ref, cfg: MapperConfig,
             s1_rows.append(aligned)
             meta.append((i, int(p), mate_strand))
     if not s1_rows:
-        return 0
+        return 0, 0, 0
     # pad the stacked sweep to a pow-2 bucket: the banded WF is jitted
     # per static shape, and the rescue workload varies every chunk — the
     # bucket makes shapes repeat so streams hit the compile cache
@@ -260,7 +274,7 @@ def _rescue(res_un, res_an, idx, reads_un, ref, cfg: MapperConfig,
             res_un.distance2[i] = d
         rescued[i] = True
         n_rescued += 1
-    return n_rescued
+    return n_rows, cap, n_rescued
 
 
 def compute_mapq(distance, distance2, mapped, *, sat: int,
@@ -325,76 +339,80 @@ def resolve_pairs(res1: MappingResult, res2: MappingResult, *,
     cross-contig mates from FR concordance — a chimeric pair must never
     earn 0x2 or feed the insert tracker, even during the permissive
     bootstrap window.  Returns a ``PairResolution``; the inputs are not
-    mutated.
+    mutated.  Traced as a ``repro/pairing`` span with the batch's
+    ``pairs`` and ``proper`` pairs.
     """
     n = len(res1.position)
     if len(res2.position) != n:
         raise ValueError(f"mate result batches must align pairwise: "
                          f"{n} vs {len(res2.position)}")
-    tracker = tracker if tracker is not None else InsertSizeTracker()
-    res1, res2 = _copy_result(res1), _copy_result(res2)
-    m1, m2 = np.asarray(res1.mapped, bool), np.asarray(res2.mapped, bool)
-    s1, s2 = _strands(res1), _strands(res2)
+    with _tracing.span("repro/pairing", pairs=n) as sp:
+        tracker = tracker if tracker is not None else InsertSizeTracker()
+        res1, res2 = _copy_result(res1), _copy_result(res2)
+        m1, m2 = np.asarray(res1.mapped, bool), np.asarray(res2.mapped, bool)
+        s1, s2 = _strands(res1), _strands(res2)
 
-    def _concordant(mapped_both):
-        fr, ins = _fr_geometry(res1.position, s1, res2.position, s2,
-                               cfg.read_len)
-        fr &= mapped_both
-        if contig_starts is not None:
-            fr &= _same_contig(res1.position, res2.position, contig_starts)
-        return fr, ins
+        def _concordant(mapped_both):
+            fr, ins = _fr_geometry(res1.position, s1, res2.position, s2,
+                                   cfg.read_len)
+            fr &= mapped_both
+            if contig_starts is not None:
+                fr &= _same_contig(res1.position, res2.position, contig_starts)
+            return fr, ins
 
-    both = m1 & m2
-    fr, insert = _concordant(both)
-    tracker.update(insert[fr])  # observe before judging: running median
+        both = m1 & m2
+        fr, insert = _concordant(both)
+        tracker.update(insert[fr])  # observe before judging: running median
 
-    n_rescued = 0
-    rescued1 = np.zeros(n, dtype=bool)
-    rescued2 = np.zeros(n, dtype=bool)
-    win = (tracker.rescue_window() if ref is not None
-           and reads1 is not None and reads2 is not None else None)
-    if win is not None:
-        max_dist = cfg.eth if rescue_max_dist is None else rescue_max_dist
-        # quarantined reads (resilience layer: block failed after retries)
-        # carry synthesized unmapped rows — their bases never went through
-        # the engine, so they must neither anchor a rescue nor be rescued
-        f1 = res1.failed if res1.failed is not None else np.zeros(n, bool)
-        f2 = res2.failed if res2.failed is not None else np.zeros(n, bool)
-        only1 = np.flatnonzero(m1 & ~m2 & ~f1 & ~f2)
-        only2 = np.flatnonzero(m2 & ~m1 & ~f1 & ~f2)
-        n_rescued += _rescue(res2, res1, only1, np.asarray(reads2),
-                             ref, cfg, win, max_dist, rescue_max_windows,
-                             rescued2)
-        n_rescued += _rescue(res1, res2, only2, np.asarray(reads1),
-                             ref, cfg, win, max_dist, rescue_max_windows,
-                             rescued1)
-        if n_rescued:  # rescued placements can complete proper pairs
-            m1 = np.asarray(res1.mapped, bool)
-            m2 = np.asarray(res2.mapped, bool)
-            both = m1 & m2
-            s1, s2 = _strands(res1), _strands(res2)
-            fr, insert = _concordant(both)
+        n_rescued = 0
+        rescued1 = np.zeros(n, dtype=bool)
+        rescued2 = np.zeros(n, dtype=bool)
+        win = (tracker.rescue_window() if ref is not None
+               and reads1 is not None and reads2 is not None else None)
+        if win is not None:
+            max_dist = cfg.eth if rescue_max_dist is None else rescue_max_dist
+            # quarantined reads (resilience layer: block failed after
+            # retries) carry synthesized unmapped rows — their bases never
+            # went through the engine, so they must neither anchor a rescue
+            # nor be rescued
+            f1 = res1.failed if res1.failed is not None else np.zeros(n, bool)
+            f2 = res2.failed if res2.failed is not None else np.zeros(n, bool)
+            only1 = np.flatnonzero(m1 & ~m2 & ~f1 & ~f2)
+            only2 = np.flatnonzero(m2 & ~m1 & ~f1 & ~f2)
+            n_rescued += _rescue(res2, res1, only1, np.asarray(reads2),
+                                 ref, cfg, win, max_dist, rescue_max_windows,
+                                 rescued2)
+            n_rescued += _rescue(res1, res2, only2, np.asarray(reads1),
+                                 ref, cfg, win, max_dist, rescue_max_windows,
+                                 rescued1)
+            if n_rescued:  # rescued placements can complete proper pairs
+                m1 = np.asarray(res1.mapped, bool)
+                m2 = np.asarray(res2.mapped, bool)
+                both = m1 & m2
+                s1, s2 = _strands(res1), _strands(res2)
+                fr, insert = _concordant(both)
 
-    lo, hi = tracker.window()
-    proper = fr & (insert >= lo) & (insert <= hi)
-    insert = np.where(fr, insert, 0).astype(np.int32)
+        lo, hi = tracker.window()
+        proper = fr & (insert >= lo) & (insert <= hi)
+        insert = np.where(fr, insert, 0).astype(np.int32)
 
-    mapq1 = compute_mapq(res1.distance, res1.distance2, m1,
-                         sat=cfg.sat_affine, proper=proper, mate_mapped=m2)
-    mapq2 = compute_mapq(res2.distance, res2.distance2, m2,
-                         sat=cfg.sat_affine, proper=proper, mate_mapped=m1)
-    # a rescued mate exists only because its anchor placed it: its
-    # confidence cannot exceed the anchor's
-    mapq2 = np.where(rescued2, np.minimum(np.minimum(mapq1, _RESCUE_CAP),
-                                          mapq2), mapq2)
-    mapq1 = np.where(rescued1, np.minimum(np.minimum(mapq2, _RESCUE_CAP),
-                                          mapq1), mapq1)
+        mapq1 = compute_mapq(res1.distance, res1.distance2, m1,
+                             sat=cfg.sat_affine, proper=proper, mate_mapped=m2)
+        mapq2 = compute_mapq(res2.distance, res2.distance2, m2,
+                             sat=cfg.sat_affine, proper=proper, mate_mapped=m1)
+        # a rescued mate exists only because its anchor placed it: its
+        # confidence cannot exceed the anchor's
+        mapq2 = np.where(rescued2, np.minimum(np.minimum(mapq1, _RESCUE_CAP),
+                                              mapq2), mapq2)
+        mapq1 = np.where(rescued1, np.minimum(np.minimum(mapq2, _RESCUE_CAP),
+                                              mapq1), mapq1)
 
-    stats = dict(n_pairs=n, n_both_mapped=int(both.sum()),
-                 n_proper=int(proper.sum()), n_rescued=n_rescued,
-                 n_discordant=int((both & ~proper).sum()),
-                 insert_median=tracker.median,
-                 insert_window=(lo, hi))
+        stats = dict(n_pairs=n, n_both_mapped=int(both.sum()),
+                     n_proper=int(proper.sum()), n_rescued=n_rescued,
+                     n_discordant=int((both & ~proper).sum()),
+                     insert_median=tracker.median,
+                     insert_window=(lo, hi))
+        sp.set_metadata(proper=stats["n_proper"])
     return PairResolution(res1=res1, res2=res2, proper=proper,
                           mapq1=mapq1, mapq2=mapq2,
                           rescued1=rescued1, rescued2=rescued2,

@@ -59,7 +59,7 @@ import numpy as np
 from . import affine_wf
 from . import streaming
 from . import wf_backend as wfb
-from ..obs.tracing import annotate as _annotate
+from ..obs import tracing as _tracing
 from .compaction import bucket_capacity, compact_indices, scatter_to
 from .encoding import revcomp
 from .filtering import collapse_candidates, gather_windows, linear_wf_filter
@@ -670,6 +670,12 @@ class LazyTraceback:
         return np.array(ops), np.array(cnt)
 
 
+def _host_int(x) -> int:
+    """A count read back from the device: a blocking device-to-host sync."""
+    with _tracing.span("repro/map/sync"):
+        return int(x)
+
+
 class _ChunkPipeline:
     """Phase-split per-chunk execution for the streaming engine.
 
@@ -703,20 +709,23 @@ class _ChunkPipeline:
         sub, chunk = item
         n_real = len(sub)
         t0 = time.perf_counter()
-        if n_real < chunk:  # keep the chunk shape static; trimmed in fetch
-            sub = np.concatenate(
-                [sub, np.zeros((chunk - n_real, sub.shape[1]), sub.dtype)])
-        if self.cfg.both_strands:
-            # rows [0:chunk) forward, [chunk:2*chunk) reverse complement:
-            # each chunk carries both encodings of its own reads, so the
-            # strand reduce happens on device before fetch (phase 2)
-            sub = np.concatenate([sub, revcomp(sub)])
-        t0 = streaming.timed(times, "host_prep", t0)
-        reads = jnp.asarray(sub)
-        if times is not None:
-            reads.block_until_ready()
-        t0 = streaming.timed(times, "h2d", t0)
-        with _annotate("seed_dispatch"):
+        with _tracing.span("repro/map/prep"):
+            if n_real < chunk:  # keep the chunk shape static; trimmed in fetch
+                sub = np.concatenate(
+                    [sub, np.zeros((chunk - n_real, sub.shape[1]),
+                                   sub.dtype)])
+            if self.cfg.both_strands:
+                # rows [0:chunk) forward, [chunk:2*chunk) reverse
+                # complement: each chunk carries both encodings of its own
+                # reads, so the strand reduce happens on device before
+                # fetch (phase 2)
+                sub = np.concatenate([sub, revcomp(sub)])
+            t0 = streaming.timed(times, "host_prep", t0)
+            reads = jnp.asarray(sub)
+            if times is not None:
+                reads.block_until_ready()
+            t0 = streaming.timed(times, "h2d", t0)
+        with _tracing.span("repro/map/dispatch", stage="seed"):
             seeds = seed_reads(self.dev[0], self.dev[1], reads,
                                self.cfg.seed_params)
         if times is not None:
@@ -725,16 +734,17 @@ class _ChunkPipeline:
         return reads, seeds, n_real
 
     def _real_count(self, arr, total: int, n_real: int, R: int):
-        """Host count of True entries in ``arr``'s non-padding rows.
-        ``total`` is the known full count; a partial chunk re-counts over
-        the real slice of each strand half."""
+        """Host count of True entries in ``arr``'s non-padding rows, and
+        the blocking device reads it took.  ``total`` is the known full
+        count; a partial chunk re-counts over the real slice of each
+        strand half."""
         half = R // 2 if self.cfg.both_strands else R
         if (2 * n_real if self.cfg.both_strands else n_real) == R:
-            return total
+            return total, 0
         c = jnp.sum(arr[:n_real])
         if self.cfg.both_strands:
             c = c + jnp.sum(arr[half : half + n_real])
-        return int(c)
+        return _host_int(c), 1
 
     def chunk_index(self, seeds):
         """Device ``(positions, segments)`` that this chunk's ``occ_idx``
@@ -758,14 +768,16 @@ class _ChunkPipeline:
         # count syncs happen before the stage call so the donated buffers
         # (occ_valid / pass_filter) are never read after being consumed
         t0 = time.perf_counter()
-        n_valid = int(seeds["n_valid"])
+        n_valid = _host_int(seeds["n_valid"])
         lin_cap = bucket_capacity(n_valid, align=cfg.lin_block_r,
                                   cap_max=R * M * P)
 
         if cfg.engine == "fused":
-            n_valid_real = self._real_count(occ_valid, n_valid, n_real, R)
+            n_valid_real, syncs = self._real_count(occ_valid, n_valid,
+                                                   n_real, R)
             aff_cap = fused_affine_capacity(n_valid, R, cfg)
-            with _annotate("fused_dispatch"):
+            with _tracing.span("repro/map/dispatch", stage="fused",
+                               lin_cap=lin_cap, aff_cap=aff_cap):
                 out = _fused_stage(segments, positions, reads, occ_idx,
                                    occ_valid, mini_pos, jnp.int32(n_real),
                                    cfg, lin_cap, aff_cap)
@@ -779,7 +791,8 @@ class _ChunkPipeline:
                          affine_dist_instances=aff_cap,
                          padded_affine_instances=rows_real * M,
                          affine_dirs_instances=(
-                             n_real if cfg.cigar_mode == "eager" else 0))
+                             n_real if cfg.cigar_mode == "eager" else 0),
+                         d2h_syncs=1 + syncs)
             if cfg.both_strands:
                 stats["reverse_best"] = out.pop("reverse_best")
             if profile:
@@ -787,28 +800,33 @@ class _ChunkPipeline:
                                       ("fused", out["position"]))
             return out, stats, n_real
 
-        n_valid_real = self._real_count(occ_valid, n_valid, n_real, R)
-        with _annotate("linear_dispatch"):
+        n_valid_real, syncs = self._real_count(occ_valid, n_valid, n_real, R)
+        with _tracing.span("repro/map/dispatch", stage="linear",
+                           lin_cap=lin_cap):
             lin_end, best_pl, pass_filter, n_cand = self.lin_jit(
                 segments, reads, occ_idx, occ_valid, mini_pos, cfg, lin_cap)
         if times is not None:
             pass_filter.block_until_ready()
         t0 = streaming.timed(times, "linear", t0)
 
-        n_surv = int(jnp.sum(pass_filter))
-        n_surv_real = self._real_count(pass_filter, n_surv, n_real, R)
+        n_surv = _host_int(jnp.sum(pass_filter))
+        n_surv_real, surv_syncs = self._real_count(pass_filter, n_surv,
+                                                   n_real, R)
+        syncs += 2 + surv_syncs
         aff_cap = bucket_capacity(n_surv, align=cfg.aff_block_r,
                                   cap_max=R * M)
-        with _annotate("affine_dispatch"):
+        with _tracing.span("repro/map/dispatch", stage="affine",
+                           aff_cap=aff_cap):
             (best_aff, mapped, position, best_m, distance2, occ_w,
              mpos_w) = self.aff_jit(segments, positions, reads, occ_idx,
                                     mini_pos, best_pl, pass_filter, lin_end,
                                     cfg, aff_cap)
         reads_w, strand, reverse_best = reads, None, None
         if cfg.both_strands:
-            fold = _strand_stage(best_aff, mapped, position, distance2,
-                                 n_cand, occ_w, mpos_w, reads, lin_end,
-                                 jnp.int32(n_real), cfg)
+            with _tracing.span("repro/map/dispatch", stage="strand"):
+                fold = _strand_stage(best_aff, mapped, position, distance2,
+                                     n_cand, occ_w, mpos_w, reads, lin_end,
+                                     jnp.int32(n_real), cfg)
             best_aff, mapped, position = (fold["distance"], fold["mapped"],
                                           fold["position"])
             distance2, n_cand = fold["distance2"], fold["n_candidates"]
@@ -827,7 +845,7 @@ class _ChunkPipeline:
             out["strand"] = strand
         tb_mark = position
         if cfg.cigar_mode == "eager":
-            with _annotate("traceback_dispatch"):
+            with _tracing.span("repro/map/dispatch", stage="traceback"):
                 out["ops"], out["op_count"] = _traceback_stage(
                     segments, reads_w, occ_w, mpos_w, mapped, cfg)
             tb_mark = out["ops"]
@@ -844,7 +862,8 @@ class _ChunkPipeline:
                      affine_dist_instances=aff_cap,
                      padded_affine_instances=rows_real * M,
                      affine_dirs_instances=(
-                         n_real if cfg.cigar_mode == "eager" else 0))
+                         n_real if cfg.cigar_mode == "eager" else 0),
+                     d2h_syncs=syncs)
         if reverse_best is not None:
             stats["reverse_best"] = reverse_best
         if profile:
@@ -857,14 +876,19 @@ class _ChunkPipeline:
         out, stats, n_real = state
         mil = out.pop("_milestones", None)
         t0 = time.perf_counter()
-        if mil is not None:  # streamed profiling: completion-time offsets
-            for name, arr in mil:
-                arr.block_until_ready()
-                t0 = streaming.timed(times, name, t0)
-        host = {k: np.asarray(v)[:n_real] for k, v in out.items()}
-        streaming.timed(times, "d2h", t0)
-        stats = {k: (int(v) if isinstance(v, jax.Array) else v)
-                 for k, v in stats.items()}
+        with _tracing.span("repro/map/fetch") as sp:
+            if mil is not None:  # streamed profiling: completion offsets
+                for name, arr in mil:
+                    arr.block_until_ready()
+                    t0 = streaming.timed(times, name, t0)
+            host = {k: np.asarray(v)[:n_real] for k, v in out.items()}
+            streaming.timed(times, "d2h", t0)
+            arrays = len(out) + sum(isinstance(v, jax.Array)
+                                    for v in stats.values())
+            stats = {k: (int(v) if isinstance(v, jax.Array) else v)
+                     for k, v in stats.items()}
+            sp.set_metadata(arrays=arrays)
+        stats["d2h_syncs"] += arrays
         return host, stats
 
 

@@ -142,14 +142,19 @@ def stream_map(items: list, phase1, phase2, fetch,
             injector.check("fetch_error")
             return fetch(outs, times_)
 
-    # chunk attribution for span tracing: each phase call stamps the
-    # in-flight chunk index on whichever thread runs it, so overlapping
-    # chunks untangle in the exported trace
-    tracing_on = _tracing.ACTIVE is not None
+    # chunk attribution for spans: each phase call runs under the chunk's
+    # context on whichever thread runs it, so overlapping chunks untangle
+    # in a trace.  A caller that set a ``chunk`` (a FASTQ stream's ordinal)
+    # keeps it for every phase; otherwise each phase gets its index here.
+    caller = _tracing.get_ctx()
 
-    def fetch_job(i, outs):
-        if tracing_on:
-            _tracing.set_ctx(chunk=i)
+    def ctx(i):
+        if caller is not None and "chunk" in caller:
+            return caller
+        return {**(caller or {}), "chunk": i}
+
+    def fetch_job(ctx_i, outs):
+        _tracing.set_ctx(**ctx_i)
         return run_fetch(outs, times)
 
     reg = _metrics.ACTIVE
@@ -160,8 +165,7 @@ def stream_map(items: list, phase1, phase2, fetch,
     pool = ThreadPoolExecutor(max_workers=1,
                               thread_name_prefix="stream-fetch")
     try:
-        if tracing_on:
-            _tracing.set_ctx(chunk=0)
+        _tracing.set_ctx(**ctx(0))
         state = phase1(items[0])
         for i in range(n):
             # prompt propagation: if an already-completed fetch failed,
@@ -169,13 +173,11 @@ def stream_map(items: list, phase1, phase2, fetch,
             for f in futs[:i]:
                 if f is not None and f.done():
                     f.result()
-            if tracing_on:
-                _tracing.set_ctx(chunk=i + 1)
+            _tracing.set_ctx(**ctx(i + 1))
             nxt = phase1(items[i + 1]) if i + 1 < n else None
-            if tracing_on:
-                _tracing.set_ctx(chunk=i)
+            _tracing.set_ctx(**ctx(i))
             outs = phase2(state)
-            futs[i] = pool.submit(fetch_job, i, outs)
+            futs[i] = pool.submit(fetch_job, ctx(i), outs)
             state = nxt
         out = []
         for i, f in enumerate(futs):
@@ -193,6 +195,7 @@ def stream_map(items: list, phase1, phase2, fetch,
                     f"thread is stalled") from None
         return out
     finally:
+        _tracing.set_ctx(**(caller or {}))
         if pool is not None:
             pool.shutdown(wait=True)
 
@@ -209,11 +212,14 @@ def sync_map(items: list, phase1, phase2, fetch,
     reg = _metrics.ACTIVE
     if reg is not None:
         reg.counter("repro_chunks_total", mode="sync").inc(len(items))
-    tracing_on = _tracing.ACTIVE is not None
+    caller = _tracing.get_ctx()
     out = []
-    for i, item in enumerate(items):
-        if tracing_on:
-            _tracing.set_ctx(chunk=i)
-        out.append(fetch(phase2(phase1(item, times=times), times=times),
-                         times=times))
+    try:
+        for i, item in enumerate(items):
+            if caller is None or "chunk" not in caller:
+                _tracing.set_ctx(**(caller or {}), chunk=i)
+            out.append(fetch(phase2(phase1(item, times=times), times=times),
+                             times=times))
+    finally:
+        _tracing.set_ctx(**(caller or {}))
     return out

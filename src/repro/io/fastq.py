@@ -43,12 +43,14 @@ tail becomes a counted rejection instead of an exception.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import re
 from typing import Iterator
 
 import numpy as np
 
 from ..core.encoding import encode_str
+from ..obs import tracing as _tracing
 
 DEFAULT_CHUNK_READS = 1024
 
@@ -321,6 +323,9 @@ class FastqStream:
                 return
 
     def __iter__(self) -> Iterator[ReadChunk]:
+        return _traced(self._chunks(), len)
+
+    def _chunks(self) -> Iterator[ReadChunk]:
         rl = self.read_len
         names, reads, quals, seqs = [], [], [], []
         try:
@@ -354,6 +359,24 @@ class FastqStream:
             if self._owned:
                 self._f.close()
             self._sink.close()
+
+
+def _traced(chunks, n_reads):
+    """Yield ``chunks``, each pulled inside a ``repro/fastq`` span with its
+    ``reads`` (``n_reads(chunk)``) and under the thread context
+    ``chunk=<ordinal in the stream>``, which the spans of the layers that
+    take the chunk next (mapper, pairing, SAM) carry too."""
+    try:
+        for k in itertools.count():
+            _tracing.set_ctx(chunk=k)
+            with _tracing.span("repro/fastq") as sp:
+                chunk = next(chunks, None)
+                sp.set_metadata(reads=0 if chunk is None else n_reads(chunk))
+            if chunk is None:
+                return
+            yield chunk
+    finally:
+        chunks.close()
 
 
 def parse_fastq(path_or_handle, read_len: int | None = None,
@@ -526,6 +549,9 @@ class PairedFastqStream:
                               (self._s2, r2, l2))
 
     def __iter__(self) -> Iterator[tuple[ReadChunk, ReadChunk]]:
+        return _traced(self._chunks(), lambda pair: 2 * len(pair[0]))
+
+    def _chunks(self) -> Iterator[tuple[ReadChunk, ReadChunk]]:
         rl = self.read_len
         c1, c2 = _ChunkBuilder(rl), _ChunkBuilder(rl)
         try:

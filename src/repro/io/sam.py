@@ -28,6 +28,7 @@ from typing import Iterable, Iterator
 import numpy as np
 
 from ..core.encoding import decode_to_str, revcomp
+from ..obs import tracing as _tracing
 from .cigar import (cigar_from_ops, cigar_query_len, cigar_ref_len,
                     parse_cigar, trim_edge_deletions, unparse_cigar)
 from .fasta import Contig, ReferenceMap
@@ -124,18 +125,22 @@ def emit_alignments(result, names: list[str], reads: np.ndarray,
     Pass ``seqs`` (the raw FASTQ sequence text, e.g. ``ReadChunk.seqs``)
     to emit SEQ verbatim — the engine's codes rewrite N to A for k-mer
     seeding, and SAM output must not present those as real A bases.
+
+    Traced as one ``repro/sam`` span with the batch's ``records``, from
+    the first record requested until the generator is exhausted.
     """
-    for i, name in enumerate(names):
-        if not result.mapped[i]:
-            seq = seqs[i] if seqs is not None else decode_to_str(reads[i])
-            yield sam_record(name, FLAG_UNMAPPED, "*", 0, 0, "*",
-                             seq, _qual_str(quals[i]))
-            continue
-        contig, local, cig, seq, qual, rev = _mapped_fields(
-            result, i, reads, quals, seqs, refmap)
-        yield sam_record(name, FLAG_REVERSE if rev else 0, contig.name,
-                         local + 1, MAPQ_UNAVAILABLE, cig, seq,
-                         qual, nm=int(result.distance[i]))
+    with _tracing.span("repro/sam", records=len(names)):
+        for i, name in enumerate(names):
+            if not result.mapped[i]:
+                seq = seqs[i] if seqs is not None else decode_to_str(reads[i])
+                yield sam_record(name, FLAG_UNMAPPED, "*", 0, 0, "*",
+                                 seq, _qual_str(quals[i]))
+                continue
+            contig, local, cig, seq, qual, rev = _mapped_fields(
+                result, i, reads, quals, seqs, refmap)
+            yield sam_record(name, FLAG_REVERSE if rev else 0, contig.name,
+                             local + 1, MAPQ_UNAVAILABLE, cig, seq,
+                             qual, nm=int(result.distance[i]))
 
 
 def emit_paired_alignments(pairs, names: list[str],
@@ -153,73 +158,75 @@ def emit_paired_alignments(pairs, names: list[str],
     symmetric TLEN (leftmost mate positive; ties broken toward R1), plus
     the calibrated MAPQ from the pair resolution.  Unmapped mates keep
     the validator's unmapped shape (RNAME ``*``, POS 0, CIGAR ``*``) but
-    still point RNEXT/PNEXT at a mapped mate's locus.
+    still point RNEXT/PNEXT at a mapped mate's locus.  Traced as
+    ``emit_alignments`` is.
     """
-    res = (pairs.res1, pairs.res2)
-    reads = (reads1, reads2)
-    quals = (quals1, quals2)
-    seqs = (seqs1, seqs2)
-    mapqs = (pairs.mapq1, pairs.mapq2)
-    mate_flag = (FLAG_READ1, FLAG_READ2)
-    for i, name in enumerate(names):
-        mapped = [bool(res[m].mapped[i]) for m in (0, 1)]
-        fields = [
-            _mapped_fields(res[m], i, reads[m], quals[m], seqs[m], refmap)
-            if mapped[m] else None
-            for m in (0, 1)]
-        proper = bool(pairs.proper[i])
-        # reference footprint per mate (for TLEN): CIGAR when present,
-        # read length otherwise (the mesh path's CIGAR-less records)
-        span = [None, None]
-        for m in (0, 1):
-            if mapped[m]:
-                contig, local, cig, _, _, _ = fields[m]
-                ref_len = (cigar_ref_len(cig) if cig != "*"
-                           else np.asarray(reads[m]).shape[1])
-                span[m] = (contig, local, local + ref_len)
-        tlen = [0, 0]
-        if mapped[0] and mapped[1] and span[0][0] is span[1][0]:
-            lo = min(span[0][1], span[1][1])
-            hi = max(span[0][2], span[1][2])
-            if (span[0][1], 0) <= (span[1][1], 1):  # ties: R1 leftmost
-                tlen = [hi - lo, lo - hi]
-            else:
-                tlen = [lo - hi, hi - lo]
-        for m in (0, 1):
-            o = 1 - m
-            flag = FLAG_PAIRED | mate_flag[m]
-            if proper:
-                flag |= FLAG_PROPER
-            if not mapped[m]:
-                flag |= FLAG_UNMAPPED
-            if not mapped[o]:
-                flag |= FLAG_MATE_UNMAPPED
-            if mapped[o] and fields[o][5]:
-                flag |= FLAG_MATE_REVERSE
-            if not mapped[m]:
-                seq = (seqs[m][i] if seqs[m] is not None
-                       else decode_to_str(reads[m][i]))
+    with _tracing.span("repro/sam", records=2 * len(names)):
+        res = (pairs.res1, pairs.res2)
+        reads = (reads1, reads2)
+        quals = (quals1, quals2)
+        seqs = (seqs1, seqs2)
+        mapqs = (pairs.mapq1, pairs.mapq2)
+        mate_flag = (FLAG_READ1, FLAG_READ2)
+        for i, name in enumerate(names):
+            mapped = [bool(res[m].mapped[i]) for m in (0, 1)]
+            fields = [
+                _mapped_fields(res[m], i, reads[m], quals[m], seqs[m], refmap)
+                if mapped[m] else None
+                for m in (0, 1)]
+            proper = bool(pairs.proper[i])
+            # reference footprint per mate (for TLEN): CIGAR when present,
+            # read length otherwise (the mesh path's CIGAR-less records)
+            span = [None, None]
+            for m in (0, 1):
+                if mapped[m]:
+                    contig, local, cig, _, _, _ = fields[m]
+                    ref_len = (cigar_ref_len(cig) if cig != "*"
+                               else np.asarray(reads[m]).shape[1])
+                    span[m] = (contig, local, local + ref_len)
+            tlen = [0, 0]
+            if mapped[0] and mapped[1] and span[0][0] is span[1][0]:
+                lo = min(span[0][1], span[1][1])
+                hi = max(span[0][2], span[1][2])
+                if (span[0][1], 0) <= (span[1][1], 1):  # ties: R1 leftmost
+                    tlen = [hi - lo, lo - hi]
+                else:
+                    tlen = [lo - hi, hi - lo]
+            for m in (0, 1):
+                o = 1 - m
+                flag = FLAG_PAIRED | mate_flag[m]
+                if proper:
+                    flag |= FLAG_PROPER
+                if not mapped[m]:
+                    flag |= FLAG_UNMAPPED
+                if not mapped[o]:
+                    flag |= FLAG_MATE_UNMAPPED
+                if mapped[o] and fields[o][5]:
+                    flag |= FLAG_MATE_REVERSE
+                if not mapped[m]:
+                    seq = (seqs[m][i] if seqs[m] is not None
+                           else decode_to_str(reads[m][i]))
+                    rnext, pnext = "*", 0
+                    if mapped[o]:  # point at the mate so the pair stays
+                        #            co-locatable in sorted output
+                        rnext = fields[o][0].name
+                        pnext = fields[o][1] + 1
+                    yield sam_record(name, flag, "*", 0, 0, "*", seq,
+                                     _qual_str(quals[m][i]), rnext=rnext,
+                                     pnext=pnext, tlen=0)
+                    continue
+                contig, local, cig, seq, qual, rev = fields[m]
+                if rev:
+                    flag |= FLAG_REVERSE
                 rnext, pnext = "*", 0
-                if mapped[o]:  # point at the mate so the pair stays
-                    #            co-locatable in sorted output
-                    rnext = fields[o][0].name
-                    pnext = fields[o][1] + 1
-                yield sam_record(name, flag, "*", 0, 0, "*", seq,
-                                 _qual_str(quals[m][i]), rnext=rnext,
-                                 pnext=pnext, tlen=0)
-                continue
-            contig, local, cig, seq, qual, rev = fields[m]
-            if rev:
-                flag |= FLAG_REVERSE
-            rnext, pnext = "*", 0
-            if mapped[o]:
-                o_contig, o_local = fields[o][0], fields[o][1]
-                rnext = "=" if o_contig is contig else o_contig.name
-                pnext = o_local + 1
-            yield sam_record(name, flag, contig.name, local + 1,
-                             int(mapqs[m][i]), cig, seq, qual,
-                             rnext=rnext, pnext=pnext, tlen=tlen[m],
-                             nm=int(res[m].distance[i]))
+                if mapped[o]:
+                    o_contig, o_local = fields[o][0], fields[o][1]
+                    rnext = "=" if o_contig is contig else o_contig.name
+                    pnext = o_local + 1
+                yield sam_record(name, flag, contig.name, local + 1,
+                                 int(mapqs[m][i]), cig, seq, qual,
+                                 rnext=rnext, pnext=pnext, tlen=tlen[m],
+                                 nm=int(res[m].distance[i]))
 
 
 def write_sam(handle, header_lines: Iterable[str],

@@ -30,7 +30,6 @@ pipes clean SAM to stdout.
 from __future__ import annotations
 
 import argparse
-import contextlib
 import json
 import os
 import sys
@@ -63,34 +62,6 @@ def _open_stream(args, injector=None):
     if args.interleaved:
         return PairedFastqStream(args.reads, interleaved=True, **kw), True
     return FastqStream(args.reads, **kw), False
-
-
-def _ingest(stream):
-    """Enumerate FASTQ chunks, stamping the span context with the chunk
-    index and recording each chunk's host-side parse as an ``ingest``
-    span when tracing is armed."""
-    from repro.obs import tracing as _tracing
-    it = iter(stream)
-    i = 0
-    while True:
-        if _tracing.ACTIVE is not None:
-            _tracing.set_ctx(chunk=i)
-        t0 = time.perf_counter()
-        try:
-            chunk = next(it)
-        except StopIteration:
-            return
-        tr = _tracing.ACTIVE
-        if tr is not None:
-            tr.add("ingest", t0, time.perf_counter())
-        yield i, chunk
-        i += 1
-
-
-def _span(name):
-    from repro.obs import tracing as _tracing
-    tr = _tracing.ACTIVE
-    return tr.span(name) if tr is not None else contextlib.nullcontext()
 
 
 def _metrics_snapshot(path, seq: int) -> None:
@@ -258,7 +229,7 @@ def _run(args) -> int:
             out.write(line + "\n")
         t_map = time.perf_counter()
         n_chunks = 0
-        for i, chunk in _ingest(stream):
+        for i, chunk in enumerate(stream):
             n_chunks = i + 1
             if paired:
                 c1, c2 = chunk
@@ -276,11 +247,10 @@ def _run(args) -> int:
                                    ref=ref, reads1=c1.reads,
                                    reads2=c2.reads,
                                    contig_starts=contig_starts)
-                with _span("sam_emit"):
-                    for rec in emit_paired_alignments(
-                            pr, c1.names, c1.reads, c1.quals, c2.reads,
-                            c2.quals, refmap, seqs1=c1.seqs, seqs2=c2.seqs):
-                        out.write(rec + "\n")
+                for rec in emit_paired_alignments(
+                        pr, c1.names, c1.reads, c1.quals, c2.reads,
+                        c2.quals, refmap, seqs1=c1.seqs, seqs2=c2.seqs):
+                    out.write(rec + "\n")
                 n_new = 2 * len(c1)
                 n_mapped = int(pr.res1.mapped.sum() + pr.res2.mapped.sum())
                 res = res1  # stats object is shared by both halves
@@ -305,11 +275,10 @@ def _run(args) -> int:
                         continue
                 else:
                     res = mapper.map(chunk.reads)
-                with _span("sam_emit"):
-                    for rec in emit_alignments(res, chunk.names,
-                                               chunk.reads, chunk.quals,
-                                               refmap, seqs=chunk.seqs):
-                        out.write(rec + "\n")
+                for rec in emit_alignments(res, chunk.names, chunk.reads,
+                                           chunk.quals, refmap,
+                                           seqs=chunk.seqs):
+                    out.write(rec + "\n")
                 n_new = len(chunk)
                 n_mapped = int(res.mapped.sum())
                 if res.strand is not None:  # from the result, not stats:

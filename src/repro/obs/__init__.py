@@ -21,13 +21,13 @@ The package is a **leaf**: nothing here imports ``repro.core`` or
 from . import logjson, server, validate
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        disable_metrics, enable_metrics, metrics)
-from .tracing import (Tracer, annotate, clear_ctx, disable_tracing,
-                      enable_tracing, get_ctx, set_ctx, tracer)
+from .tracing import (Tracer, clear_ctx, disable_tracing, enable_tracing,
+                      get_ctx, set_ctx, span, tracer)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "enable_metrics", "disable_metrics", "metrics",
     "Tracer", "enable_tracing", "disable_tracing", "tracer",
-    "set_ctx", "get_ctx", "clear_ctx", "annotate",
+    "set_ctx", "get_ctx", "clear_ctx", "span",
     "logjson", "server", "validate",
 ]

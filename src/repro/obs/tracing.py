@@ -12,25 +12,38 @@ two clock reads**, so the exported trace's per-stage durations and the
 legacy ``stage_times_s`` dict are identical by construction — the
 acceptance property ``tests/test_obs.py`` locks.
 
-Attribution rides a thread-local context (``set_ctx(chunk=i)``): the
-streaming engine stamps the in-flight chunk index on whichever thread
-(dispatch or fetch) runs each phase, so overlapping chunks untangle in
-the viewer.  Memory is bounded by ``max_events`` — a long run drops and
-counts excess events rather than growing without limit.
+Attribution rides a thread-local context (``set_ctx(chunk=i)``): a FASTQ
+stream stamps each chunk's ordinal, and the streaming engine carries it
+(or, where the caller set none, the in-flight chunk index) to whichever
+thread (dispatch or fetch) runs each phase, so overlapping chunks
+untangle in the viewer.  Memory is bounded by ``max_events`` — a long
+run drops and counts excess events rather than growing without limit.
+
+The program's layers mark their work with ``span(name, **counts)``
+(names under ``repro/``: ``repro/fastq``, ``repro/map`` and its
+``prep``/``dispatch``/``sync``/``fetch`` children, ``repro/pairing``,
+``repro/pairing/rescue``, ``repro/sam``).  A span goes to every sink
+that is recording: a ``jax.profiler`` trace (profiler server,
+``jax.profiler.trace`` or ``start_trace``), where it is a
+``TraceAnnotation`` on the host plane, on the device ops' clock, with
+the thread's context and the counts as event stats; and the armed
+``Tracer``, for ``--trace-out``.
 
 Like the registry, this module is a leaf with a module-global ``ACTIVE``
-tracer: disabled cost is one attribute load + ``is None`` branch.
+tracer: with no sink recording, a span costs the profiler's flag check
+and one attribute load.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Tracer", "enable_tracing", "disable_tracing", "tracer",
-           "set_ctx", "get_ctx", "clear_ctx", "annotate"]
+           "set_ctx", "get_ctx", "clear_ctx", "span"]
 
 _tls = threading.local()
 
@@ -71,14 +84,6 @@ class Tracer:
                 return
             self._events.append(
                 (name, threading.get_ident(), t0, t1, args))
-
-    @contextlib.contextmanager
-    def span(self, name: str, **args):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, t0, time.perf_counter(), args or None)
 
     def __len__(self) -> int:
         return len(self._events)
@@ -148,15 +153,65 @@ def tracer() -> Tracer | None:
     return ACTIVE
 
 
-def annotate(name: str):
-    """``jax.profiler.TraceAnnotation`` when tracing is armed, else a
-    null context — the hook that names engine dispatches inside a
-    ``jax.profiler`` trace (profiler server / programmatic traces)
-    without taxing un-traced runs."""
-    if ACTIVE is None:
-        return contextlib.nullcontext()
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:   # profiler unavailable: spans still work
-        return contextlib.nullcontext()
+class _NullSpan:
+    """The span of a process with no sink recording: does nothing."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **counts) -> None:
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _Span:
+    __slots__ = ("name", "args", "tracer", "profiling", "ann", "t0")
+
+    def __init__(self, name: str, args: dict, tracer_, profiling: bool):
+        self.name, self.args, self.tracer = name, args, tracer_
+        self.profiling, self.ann = profiling, None
+
+    def __enter__(self):
+        ctx = getattr(_tls, "ctx", None)
+        if ctx:
+            self.args = {**ctx, **self.args}
+        if self.profiling:
+            self.ann = TraceAnnotation(self.name, **self.args)
+            self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def set_metadata(self, **counts) -> None:
+        """Counts known only at the end of the span."""
+        self.args.update(counts)
+        if self.ann is not None:
+            self.ann.set_metadata(**counts)
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        if self.tracer is not None:
+            self.tracer.add(self.name, self.t0, t1, self.args)
+        return False
+
+
+def span(name: str, **counts):
+    """A context manager marking one layer's work (per chunk or per call,
+    never per record): a ``jax.profiler.TraceAnnotation`` named ``name``
+    while a profiler records, with the thread's context (``chunk``) and
+    ``counts`` as its stats, and a span of the armed ``Tracer`` from the
+    same two clock reads.  ``set_metadata(**counts)`` on the entered span
+    adds counts known only at its end.  With neither recording it does
+    nothing beyond the profiler's flag check."""
+    tr = ACTIVE
+    profiling = TraceAnnotation.is_enabled()
+    if tr is None and not profiling:
+        return _NULL_SPAN
+    return _Span(name, counts, tr, profiling)

@@ -108,9 +108,9 @@ def test_prometheus_exposition_format():
 # ----------------------------------------------------------------- tracing
 
 def test_tracer_spans_ctx_and_chrome_export():
-    tr = Tracer()
+    tr = obs_tracing.enable_tracing(tracer_=Tracer())
     obs_tracing.set_ctx(chunk=3)
-    with tr.span("work", shard=1):
+    with obs_tracing.span("work", shard=1):
         pass
     obs_tracing.clear_ctx()
     assert len(tr) == 1
@@ -353,3 +353,225 @@ def test_metrics_server_round_trip():
         assert snap["counters"]["up_total"] == 1
     finally:
         srv.stop()
+
+
+# ------------------------------------------- layer spans in a profiler trace
+
+SPAN_NAMES = ("repro/fastq", "repro/map", "repro/map/prep",
+              "repro/map/dispatch", "repro/map/sync", "repro/map/fetch",
+              "repro/pairing", "repro/pairing/rescue", "repro/sam")
+
+
+@pytest.fixture(scope="module")
+def paired_world(tmp_path_factory):
+    """Two chunks of 12 pairs in R1/R2 FASTQ files, a quarter of the R2
+    mates random so that rescue sweeps run."""
+    from repro.core.index import build_index
+    from repro.data.genome import (make_reference, sample_pairs,
+                                   write_fasta, write_fastq_pair)
+    d = tmp_path_factory.mktemp("obs_spans")
+    ref = make_reference(8_000, seed=11, repeat_frac=0.03)
+    ps = sample_pairs(ref, 24, insert_mean=300, insert_sd=20,
+                      unmappable_frac=0.25, seed=5)
+    write_fastq_pair(d / "r1.fq", d / "r2.fq", ps)
+    write_fasta(d / "ref.fa", [("chr1", ref)])
+    return d, ref, build_index(ref)
+
+
+def _map_pairs_and_emit(d, ref, idx):
+    """Map, pair and emit every chunk of the world's FASTQ pair."""
+    from repro.core.pairing import InsertSizeTracker, resolve_pairs
+    from repro.io.fasta import Contig, ReferenceMap
+    from repro.io.fastq import PairedFastqStream
+    from repro.io.sam import emit_paired_alignments
+    cfg = MapperConfig.from_index(idx, chunk_reads=16, both_strands=True)
+    mapper = Mapper(idx, cfg)
+    refmap = ReferenceMap([Contig(name="chr1", length=len(ref), offset=0)])
+    tracker = InsertSizeTracker(min_samples=4)
+    records = 0
+    for c1, c2 in PairedFastqStream(str(d / "r1.fq"), str(d / "r2.fq"),
+                                    chunk_reads=12):
+        res1, res2 = mapper.map_pairs(c1.reads, c2.reads)
+        pr = resolve_pairs(res1, res2, cfg=cfg, tracker=tracker, ref=ref,
+                           reads1=c1.reads, reads2=c2.reads)
+        records += sum(1 for _ in emit_paired_alignments(
+            pr, c1.names, c1.reads, c1.quals, c2.reads, c2.quals, refmap))
+    return records
+
+
+def _profiled(trace_dir, fn):
+    """Run ``fn`` under ``jax.profiler``; returns its result and the
+    trace's ``repro/*`` host events as (name, line, start_ns, end_ns,
+    stats), ``line`` the index of the event's thread line (every Python
+    thread's line is named ``python``)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(trace_dir / "**" / "*.xplane.pb"), recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                events += [(e.name, i, e.start_ns, e.end_ns, dict(e.stats))
+                           for e in line.events
+                           if e.name.startswith("repro/")]
+    return out, events
+
+
+def test_profiler_trace_holds_the_layer_spans(paired_world, tmp_path,
+                                              monkeypatch):
+    """Under a ``jax.profiler`` trace, with no ``Tracer`` armed, every
+    layer's span is on the host plane, nested under its parent, with its
+    chunk and counts; ``syncs`` equals the blocking reads made."""
+    import threading
+
+    import jax
+    from jax._src.array import ArrayImpl
+
+    from repro.core import pipeline
+    d, ref, idx = paired_world
+    _map_pairs_and_emit(d, ref, idx)          # compile outside the trace
+    reads_back = []                           # from both threads
+    to_int = ArrayImpl.__int__
+
+    def counting_int(x):
+        reads_back.append(threading.get_ident())
+        return to_int(x)
+
+    class CountingNumpy:
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, x, *a, **kw):
+            if isinstance(x, jax.Array):
+                reads_back.append(threading.get_ident())
+            return np.asarray(x, *a, **kw)
+
+    monkeypatch.setattr(ArrayImpl, "__int__", counting_int)
+    monkeypatch.setattr(pipeline, "np", CountingNumpy())
+    records, events = _profiled(tmp_path, lambda: _map_pairs_and_emit(
+        d, ref, idx))
+    assert records == 2 * 24
+    by = {n: [e for e in events if e[0] == n] for n in SPAN_NAMES}
+    assert all(by[n] for n in SPAN_NAMES), str(
+        [(n, len(v)) for n, v in by.items()])
+
+    def within(child, parents, same_line=True):
+        return [p for p in parents if p[2] <= child[2] and child[3] <= p[3]
+                and (p[1] == child[1] or not same_line)]
+
+    for child, parent, same_line in (
+            ("repro/map/prep", "repro/map", True),
+            ("repro/map/dispatch", "repro/map", True),
+            ("repro/map/sync", "repro/map", True),
+            ("repro/map/fetch", "repro/map", False),
+            ("repro/pairing/rescue", "repro/pairing", True)):
+        for e in by[child]:
+            enclosing = within(e, by[parent], same_line)
+            assert len(enclosing) == 1, (child, e)
+            assert enclosing[0][4]["chunk"] == e[4]["chunk"]
+    assert {e[1] for e in by["repro/map/fetch"]} != {
+        e[1] for e in by["repro/map"]}      # the fetch thread's own line
+    fastq = [e for e in by["repro/fastq"] if e[4]["reads"]]
+    assert [e[4]["chunk"] for e in fastq] == [0, 1]
+    assert [e[4]["reads"] for e in fastq] == [24, 24]
+    for name in ("repro/map", "repro/pairing", "repro/sam"):
+        assert sorted(e[4]["chunk"] for e in by[name]) == [0, 1], name
+    assert [e[4]["records"] for e in by["repro/sam"]] == [24, 24]
+    assert [e[4]["pairs"] for e in by["repro/pairing"]] == [12, 12]
+    stages = {e[4]["stage"] for e in by["repro/map/dispatch"]}
+    assert stages == {"seed", "linear", "affine", "strand", "traceback"}
+    rows = [(e[4]["rows"], e[4]["bucket"]) for e in by["repro/pairing/rescue"]]
+    assert any(r for r, _ in rows)
+    assert all(r <= b for r, b in rows)
+    assert sum(e[4]["syncs"] for e in by["repro/map"]) == len(reads_back)
+    assert len(set(reads_back)) == 2        # the dispatch and fetch threads
+
+
+def test_spans_cost_no_annotation_with_the_profiler_off(paired_world,
+                                                        monkeypatch):
+    from jax.profiler import TraceAnnotation
+    made = []
+
+    class Counting(TraceAnnotation):
+        def __init__(self, *a, **kw):
+            made.append(a)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(obs_tracing, "TraceAnnotation", Counting)
+    d, ref, idx = paired_world
+    assert _map_pairs_and_emit(d, ref, idx) == 2 * 24
+    assert made == []
+
+
+def _map_fastq_argv(d, out, *extra):
+    return [str(d / "ref.fa"), "--r1", str(d / "r1.fq"), "--r2",
+            str(d / "r2.fq"), "-o", str(out), "--chunk-reads", "12", *extra]
+
+
+def test_map_fastq_profiler_trace_without_trace_out(paired_world, tmp_path):
+    """A plain ``jax.profiler`` trace of ``map_fastq`` (no ``--trace-out``,
+    no ``Tracer``) holds the program's layer spans."""
+    from repro.launch.map_fastq import main
+    d, _, _ = paired_world
+    rc, events = _profiled(tmp_path / "trace", lambda: main(
+        _map_fastq_argv(d, tmp_path / "out.sam")))
+    assert rc == 0
+    assert obs_tracing.tracer() is None
+    assert {e[0] for e in events} == set(SPAN_NAMES)
+
+
+def test_trace_out_spans_carry_the_stream_chunk(paired_world, tmp_path):
+    """``--trace-out``: each chunk's spans carry its ordinal in the FASTQ
+    stream, and the streaming engine leaves the caller's context as it
+    found it."""
+    import json
+
+    from repro.launch.map_fastq import main
+    d, _, _ = paired_world
+    path = tmp_path / "trace.json"
+    assert main(_map_fastq_argv(d, tmp_path / "out.sam", "--trace-out",
+                                str(path))) == 0
+    trace = json.loads(path.read_text())
+    assert validate_chrome_trace(trace) == []
+    spans = [e for e in trace["traceEvents"] if e["ph"] == "X"]
+    for name in ("repro/sam", "repro/map", "repro/pairing"):
+        assert sorted(e["args"]["chunk"] for e in spans
+                      if e["name"] == name) == [0, 1], name
+    fetch = [e["args"]["chunk"] for e in spans
+             if e["name"] == "repro/map/fetch"]
+    assert sorted(set(fetch)) == [0, 1]
+
+
+def test_stream_map_restores_the_callers_context():
+    seen = []
+
+    def fetch(outs, times):
+        seen.append(obs_tracing.get_ctx()["chunk"])
+        return outs
+
+    obs_tracing.set_ctx(chunk=7)
+    assert streaming.stream_map([1, 2, 3], lambda x: x, lambda s: s,
+                                fetch) == [1, 2, 3]
+    assert obs_tracing.get_ctx() == {"chunk": 7}
+    assert seen == [7, 7, 7]
+    obs_tracing.clear_ctx()
+    streaming.stream_map([1, 2, 3], lambda x: x, lambda s: s, fetch)
+    assert seen[3:] == [0, 1, 2]
+    assert not obs_tracing.get_ctx()
+
+
+def test_span_records_to_the_tracer_with_late_counts():
+    tr = obs_tracing.enable_tracing(tracer_=Tracer())
+    obs_tracing.set_ctx(chunk=2)
+    with obs_tracing.span("repro/x", rows=3) as sp:
+        sp.set_metadata(rescued=1)
+    (ev,) = [e for e in tr.chrome()["traceEvents"] if e["ph"] == "X"]
+    assert ev["name"] == "repro/x"
+    assert ev["args"] == {"chunk": 2, "rows": 3, "rescued": 1}

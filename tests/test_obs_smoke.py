@@ -77,7 +77,7 @@ def test_trace_and_metrics_exports(fastq_world, topology):
     expected = ({"dispatch", "d2h"} if topology == "mesh"
                 else {"seed", "d2h"})
     assert expected <= names, names
-    assert "ingest" in names and "sam_emit" in names
+    assert "repro/fastq" in names and "repro/sam" in names
     # spans carry chunk attribution for the viewer
     assert any(e.get("args", {}).get("chunk") is not None
                for e in trace["traceEvents"] if e["ph"] == "X")
