@@ -30,20 +30,31 @@ def gather_windows(segments: jnp.ndarray, occ_idx: jnp.ndarray,
     ref[q - pad : q - pad + seg_len], pad = read_len + eth - k.  The read's
     expected start is (q - o) for minimizer offset o, i.e. segment-local
     index (pad - o); the WF window begins win_eth earlier.
+
+    A per-candidate dynamic slice becomes a loop over the candidates on a
+    TPU, and an element-wise gather is little faster, so this gathers whole
+    segment rows and moves each window to column 0 with a barrel shifter:
+    one select between two static slices per bit of the start.  Starts
+    wrap and clamp as ``dynamic_slice`` does it.
     """
     win_eth = eth if win_eth is None else win_eth
     assert win_eth <= eth, "segment slack only covers the indexing eth"
+    seg_len = segments.shape[-1]
     pad = read_len + eth - k
     wlen = read_len + 2 * win_eth
     starts = pad - mini_pos - win_eth  # (...,) >= eth - win_eth >= 0
-
-    def slice_one(row, start):
-        return jax.lax.dynamic_slice_in_dim(segments[row], start, wlen)
-
-    flat_rows = occ_idx.reshape(-1)
-    flat_starts = jnp.broadcast_to(starts, occ_idx.shape).reshape(-1)
-    wins = jax.vmap(slice_one)(flat_rows, flat_starts)
-    return wins.reshape(occ_idx.shape + (wlen,))
+    starts = jnp.broadcast_to(starts, occ_idx.shape).reshape(-1)
+    starts = jnp.where(starts < 0, starts + seg_len, starts)
+    starts = jnp.clip(starts, 0, seg_len - wlen)
+    nbits = max(seg_len - wlen, 1).bit_length()
+    x = segments[occ_idx.reshape(-1)]                     # (N, seg_len)
+    x = jnp.pad(x, ((0, 0), (0, wlen + (1 << nbits) - 1 - seg_len)))
+    for b in reversed(range(nbits)):
+        s = 1 << b
+        width = wlen + s - 1
+        take = ((starts >> b) & 1).astype(bool)[:, None]
+        x = jnp.where(take, x[:, s:s + width], x[:, :width])
+    return x.reshape(occ_idx.shape + (wlen,))
 
 
 @partial(jax.jit, static_argnames=("eth", "backend", "block_r"))

@@ -2,9 +2,12 @@
 
 The band has half-width ``eth`` (paper: 6); all values are saturated at
 ``eth + 1``.  Only ``2*eth + 1`` cells are live per row — DART-PIM keeps them
-in one crossbar row; we keep them in one VPU-lane-resident int8 vector and
-sweep the read length.  This module is the pure-jnp reference; the Pallas
-kernel in ``repro.kernels.linear_wf`` implements the identical recurrence.
+in one crossbar row per instance.  ``banded_wf`` keeps them lane-dense, as
+the Pallas kernel in ``repro.kernels.linear_wf`` does: instances on the
+128-wide lane axis, the band cells on sublanes, one (band, N) state swept
+over the read length, and the in-row left propagation as a log-step prefix
+scan.  Both implement the identical recurrence with bit-identical results;
+``banded_wf_numpy`` is the cell-by-cell oracle.
 
 Band coordinates: cell (i, j) of the (n+1) x (m+1) WF matrix is stored at
 ``d = j - i + eth`` (valid for |i - j| <= eth).  Row ``i`` of the band needs
@@ -71,44 +74,48 @@ def banded_wf_numpy(s1: np.ndarray, s2_window: np.ndarray, eth: int = 6):
 def banded_wf(s1: jnp.ndarray, s2_window: jnp.ndarray, eth: int = 6):
     """Batched banded WF distance. s1: (..., n), s2_window: (..., n+2*eth).
 
-    Returns (dist_end, dist_min): the paper-faithful D[n][n] and the
-    semi-global min over the last band row.  int8 arithmetic, saturated at
-    eth+1 (paper: 3-bit cells for eth=6).
+    Returns (dist_end, dist_min) int32 of the broadcast leading shape: the
+    paper-faithful D[n][n] and the semi-global min over the last band row,
+    saturated at eth+1 (paper: 3-bit cells for eth=6).
+
+    The leading dims are flattened to one instance axis N and transposed
+    onto lanes: reads (n, N), windows (n + 2*eth, N), and a (2*eth+1, N)
+    int32 band state with the band cells on sublanes.  Each row reads its
+    base and its band of window chars with a slice along the major axis.
+    The left propagation new[d] = min(sat, min over d' <= d of
+    x[d'] + d - d') is a log-step prefix scan, x = min(x, x shifted down
+    by s, + s); steps with s > eth are skipped, since they add at least
+    eth + 1 = sat and every cell is already at most sat.
     """
-    n = s1.shape[-1]
+    lead = jnp.broadcast_shapes(s1.shape[:-1], s2_window.shape[:-1])
+    n, wlen = s1.shape[-1], s2_window.shape[-1]
+    s1T = jnp.broadcast_to(s1, lead + (n,)).reshape(-1, n).T
+    s2T = jnp.broadcast_to(s2_window, lead + (wlen,)).reshape(-1, wlen).T
+    N = s1T.shape[1]
     band = 2 * eth + 1
-    sat = jnp.int8(eth + 1)
-    d_idx = jnp.arange(band, dtype=jnp.int32)
+    sat = eth + 1
+    d_col = jnp.arange(band, dtype=jnp.int32)[:, None]
 
-    b0 = jnp.where(d_idx < eth, sat, jnp.minimum(d_idx - eth, eth + 1)).astype(
-        jnp.int8
-    )
-    b0 = jnp.broadcast_to(b0, s1.shape[:-1] + (band,))
+    b0 = jnp.where(d_col < eth, sat, jnp.minimum(d_col - eth, sat))
+    b0 = jnp.broadcast_to(b0, (band, N))
 
-    def row(carry, i):
-        prev = carry  # (..., band) row i-1
-        # chars for this row: s2_window[..., i-1 : i-1+band]
-        chars = jax.lax.dynamic_slice_in_dim(s2_window, i - 1, band, axis=-1)
-        sub = (s1[..., i - 1][..., None] != chars).astype(jnp.int8)
-        j = i + d_idx - eth  # (band,)
-        diag = jnp.where(j >= 1, prev + sub, sat)
-        up_src = jnp.concatenate([prev[..., 1:], jnp.full_like(prev[..., :1], sat)],
-                                 axis=-1)
+    def row(i, B):
+        base = jax.lax.dynamic_slice_in_dim(s1T, i - 1, 1, axis=0)
+        chars = jax.lax.dynamic_slice_in_dim(s2T, i - 1, band, axis=0)
+        sub = (chars != base).astype(jnp.int32)
+        j = i + d_col - eth
+        diag = jnp.where(j >= 1, B + sub, sat)
+        up_src = jnp.concatenate([B[1:], jnp.full((1, N), sat, jnp.int32)],
+                                 axis=0)
         up = jnp.where(j >= 0, jnp.minimum(up_src + 1, sat), sat)
-        cand = jnp.minimum(jnp.minimum(diag, up), sat).astype(jnp.int8)
+        x = jnp.minimum(diag, up)
+        s = 1
+        while s <= eth:
+            sh = jnp.concatenate([jnp.full((s, N), sat, jnp.int32),
+                                  x[:band - s]], axis=0)
+            x = jnp.minimum(x, sh + s)
+            s *= 2
+        return jnp.where(j >= 0, jnp.minimum(x, sat), sat)
 
-        # left-propagation: running (min,+1) prefix scan over the band
-        def scan_left(run, c):
-            v = jnp.minimum(c, jnp.minimum(run + 1, sat)).astype(jnp.int8)
-            return v, v
-
-        init = jnp.full(cand.shape[:-1], sat, dtype=jnp.int8)
-        _, newT = jax.lax.scan(scan_left, init, jnp.moveaxis(cand, -1, 0))
-        new = jnp.moveaxis(newT, 0, -1)
-        new = jnp.where(j >= 0, new, sat).astype(jnp.int8)
-        return new, None
-
-    last, _ = jax.lax.scan(row, b0, jnp.arange(1, n + 1))
-    dist_end = last[..., eth].astype(jnp.int32)
-    dist_min = jnp.min(last, axis=-1).astype(jnp.int32)
-    return dist_end, dist_min
+    last = jax.lax.fori_loop(1, n + 1, row, b0)
+    return last[eth].reshape(lead), jnp.min(last, axis=0).reshape(lead)

@@ -6,9 +6,10 @@ VPU **lane**; the 2*eth+1 band cells live along **sublanes**.  A block of
 with a fori_loop, updating the (band, block_r) band in registers — the in-row
 dataflow of DART-PIM's Fig. 3, with MAGIC NOR ops replaced by 8x128-lane
 min/add/select.  The v5e VPU has no int8 arithmetic, so the band is int32;
-values saturate at eth + 1 exactly as in the int8 jnp reference.  The in-row
-left propagation, a sequential (min, +1) scan in the reference, is a
-log2(band)-step prefix scan here (same result: see ``_kernel``).
+values saturate at eth + 1 exactly as in the jnp reference.  The in-row left
+propagation is a log2(band)-step prefix scan (see ``_kernel``), the same
+scan the lane-dense jnp reference ``repro.core.linear_wf.banded_wf``
+runs (it stops after the steps s <= eth, the only ones that lower a cell).
 
 Inputs are pre-transposed to (seq, instances) so the instance axis is the
 (128-wide, contiguous) lane axis, and widened to int32 by ``pad_rows8``:
@@ -64,7 +65,8 @@ def _kernel(s1_ref, s2_ref, out_ref, *, eth: int, n: int):
         up = jnp.where(j >= 0, jnp.minimum(up_src + 1, sat), sat)
         x = jnp.minimum(diag, up)
         # left propagation: new[d] = min(sat, min_{d' <= d} x[d'] + d - d'),
-        # which the reference's clamped running scan also computes (x <= sat)
+        # which the numpy oracle's clamped running scan also computes
+        # (x <= sat)
         s = 1
         while s < band:
             sh = jnp.concatenate(

@@ -3,6 +3,7 @@ against the jnp references and the numpy band oracles, and compacted-vs-
 padded pipeline equivalence."""
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,6 +13,7 @@ from repro.core.affine_wf import (banded_affine, banded_affine_dist,
                                   banded_affine_numpy)
 from repro.core.compaction import (bucket_capacity, compact_indices,
                                    scatter_to)
+from repro.core.filtering import gather_windows
 from repro.core.linear_wf import banded_wf, banded_wf_numpy
 from repro.kernels import ops
 
@@ -30,6 +32,36 @@ def test_bucket_capacity_properties():
     assert bucket_capacity(10 ** 9, align=128, cap_max=6144) == 8192
     # floor: never below align
     assert bucket_capacity(1, align=512, cap_max=8192) == 512
+
+
+@pytest.mark.parametrize("read_len,k,eth,win_eth,lead", [
+    (150, 12, 6, 6, (1000,)),
+    (40, 8, 3, 3, (10, 4, 5)),
+    (150, 12, 6, 4, (7, 9)),
+], ids=["flat", "rmp", "narrow"])
+def test_gather_windows_matches_dynamic_slice(read_len, k, eth, win_eth,
+                                              lead):
+    """The barrel-shift gather picks the windows a per-candidate
+    ``dynamic_slice`` picks, start wrapping and clamping included
+    (minimizer offsets past either end of the read), for a flat candidate
+    axis, (R, M, P) rows with (R, M, 1) offsets, and a narrower window."""
+    seg_len = 2 * (read_len + eth) - k
+    wlen = read_len + 2 * win_eth
+    segments = rng.integers(0, 5, (300, seg_len)).astype(np.uint8)
+    occ = rng.integers(0, 300, lead).astype(np.int32)
+    mpos = rng.integers(-4, read_len - k + 5,
+                        lead[:-1] + (1,) if len(lead) == 3 else lead)
+    mpos = mpos.astype(np.int32)
+    got = gather_windows(jnp.asarray(segments), jnp.asarray(occ),
+                         jnp.asarray(mpos), read_len=read_len, k=k, eth=eth,
+                         win_eth=win_eth)
+    starts = np.broadcast_to(read_len + eth - k - mpos - win_eth, lead)
+    want = jax.vmap(lambda row, start: jax.lax.dynamic_slice_in_dim(
+        jnp.asarray(segments)[row], start, wlen))(
+            jnp.asarray(occ.reshape(-1)), jnp.asarray(starts.reshape(-1)))
+    assert got.shape == lead + (wlen,)
+    np.testing.assert_array_equal(np.asarray(got).reshape(-1, wlen),
+                                  np.asarray(want))
 
 
 @given(st.integers(0, 10 ** 6), st.integers(1, 300))
